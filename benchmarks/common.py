@@ -1,10 +1,11 @@
 """Shared benchmark harness: timing, store construction, CSV emission.
 
 Each fig*.py module mirrors one paper table/figure (DESIGN.md §7) and prints
-``name,us_per_call,derived`` rows. Absolute times are CPU-host numbers; the
-paper-relevant content is the RELATIVE orderings (AerialDB vs broadcast vs
-centralized, planner comparisons, failure degradation), which are
-algorithmic and transfer across hosts.
+``name,us_per_call,derived`` rows. A time is a measurement of the device it
+ran on and of nothing else: every structured row carries ``device``
+(``platform:device_kind:count`` as JAX reports it), and ``benchmarks.run``
+prints the same string in a ``# device=`` line before the rows. A row from
+the CPU backend or Pallas interpret mode says nothing about the TPU.
 """
 
 from __future__ import annotations
@@ -23,9 +24,15 @@ from repro.distributed.federation import ingest_rounds, shard_store
 ROWS = []   # structured rows, cleared per figure by run.py's --json machinery
 
 
+def device_tag() -> str:
+    """``platform:device_kind:count`` of the devices this process runs on."""
+    d = jax.devices()
+    return f"{d[0].platform}:{d[0].device_kind}:{len(d)}"
+
+
 def emit(name: str, us_per_call: float, derived: str = ""):
     ROWS.append({"name": name, "us_per_call": round(float(us_per_call), 1),
-                 "derived": derived})
+                 "derived": derived, "device": device_tag()})
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
 

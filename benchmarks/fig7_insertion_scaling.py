@@ -1,8 +1,11 @@
 """Fig 7 + §4.4.2: insertion latency D100 (20 edges) vs D400 (80 edges), the
 replica load-balance band across edges, and sharded-runtime insertion scaling
-(the paper-scale D400 config over 1/2/4/8 simulated devices — each worker
-subprocess forces its own host device count, since jax locks it at backend
-initialization).
+of the paper-scale D400 config. On the CPU backend the scaling sweep is a
+simulation over 1/2/4/8 virtual devices — each worker subprocess pins
+``JAX_PLATFORMS=cpu`` and forces its own host device count, since jax locks
+it at backend initialization. On an accelerator the sweep runs in this
+process on the real device count: a chip belongs to one process, so no
+child may need it.
 
 Balance note: the paper's §3.4.1 discusses the temporal-clustering hotspot —
 when every drone emits a shard with the SAME collection timestamp, H_t sends
@@ -16,10 +19,12 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import build_store, emit, timed_insert, timeit
+from benchmarks.fed_worker import sharded_rows
 from repro.core.placement import ShardMeta
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
@@ -42,9 +47,11 @@ def _sweep():
 
 def run_sharded_scaling(sweep=None):
     """Paper-scale 80-edge/400-drone ingest through the sharded federated
-    runtime, one subprocess per (device count, fleet count) mesh shape."""
+    runtime, one CPU-simulation subprocess per (device count, fleet count)
+    mesh shape."""
     for ndev, nfleet in (_sweep() if sweep is None else sweep):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
         src = str(REPO_ROOT / "src")
         env["PYTHONPATH"] = src + (
@@ -63,7 +70,21 @@ def run_sharded_scaling(sweep=None):
                 emit(name, float(us), derived)
 
 
+def run_sharded_in_process():
+    """The same rows on the accelerator's real devices, in this process:
+    the 1-D mesh over every device, and the 2-D mesh at two fleets."""
+    n = jax.device_count()
+    for nfleet in (1, 2):
+        if n % nfleet == 0:
+            for row in sharded_rows(n, nfleet):
+                emit(*row)
+
+
 def run():
+    if jax.default_backend() == "cpu":
+        # Simulated devices: the children are CPU-only, started before this
+        # process compiles anything.
+        run_sharded_scaling()
     for name, n_edges, n_drones in [("D100", 20, 100), ("D400", 80, 400)]:
         cfg, state, alive, fleet, _, _ = build_store(
             n_edges=n_edges, n_drones=n_drones, rounds=6, records=15,
@@ -87,6 +108,5 @@ def run():
              f"max={pe1.max()};mean={pe1.mean():.0f};"
              f"paper_s3.4.1_temporal_clustering")
 
-    # --- sharded federated runtime: D400 over 1/2/4/8 simulated devices on
-    # the 1-D mesh, plus 1/2/4 fleet partitions on the 2-D mesh ---
-    run_sharded_scaling()
+    if jax.default_backend() != "cpu":
+        run_sharded_in_process()

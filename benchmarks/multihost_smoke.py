@@ -127,6 +127,9 @@ def parent() -> None:
     coordinator = f"localhost:{port}"
 
     env = dict(os.environ)
+    # A simulation of several hosts on the CPU: the workers never take an
+    # accelerator (a chip belongs to one process).
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={DEV_PER_PROC}")
     src = str(REPO_ROOT / "src")

@@ -43,8 +43,11 @@ def main() -> None:
                     help="write BENCH_<fig>.json per figure (rows + "
                          "wall-clock + config)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import common
 
+    print(f"# device={common.device_tag()}")
     print("name,us_per_call,derived")
     t0 = time.time()
     config = _config_fingerprint() if args.json else None

@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import AerialDB
 from repro.core.datastore import StoreConfig, make_pred
 from repro.data.synthetic import CityConfig, DroneFleet, make_sites
+from repro.launch.compile_cache import enable_compile_cache
 
 # sized for this repo's 1-core CPU host; scale freely on real metal
 N_EDGES, N_DRONES, ROUNDS = 20, 50, 5
@@ -86,4 +87,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ import numpy as np            # noqa: E402
 from repro.api import AerialDB, Query, StoreConfig                       # noqa: E402
 from repro.data.synthetic import CityConfig, DroneFleet, make_sites      # noqa: E402
 from repro.launch.mesh import make_edge_mesh                             # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache              # noqa: E402
 
 
 def main():
@@ -75,4 +76,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.api import AGG_OPS, AerialDB, Query
 from repro.data.synthetic import DroneFleet
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def show(label, res, spec):
@@ -128,4 +129,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

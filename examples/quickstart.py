@@ -8,6 +8,7 @@ import numpy as np
 
 from repro.api import AerialDB, Query
 from repro.data.synthetic import CityConfig, DroneFleet, make_sites
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

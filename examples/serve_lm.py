@@ -12,6 +12,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models.model import Model
 from repro.serve.engine import Engine, ServeConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -34,4 +35,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,6 +26,7 @@ from repro.api import AerialDB, Query, StoreConfig                   # noqa: E40
 from repro.data.synthetic import CityConfig, make_sites              # noqa: E402
 from repro.ingest import IngestPipeline                              # noqa: E402
 from repro.launch.mesh import make_edge_mesh                         # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache          # noqa: E402
 
 D, R, ROUNDS = 24, 4, 3       # drones, records per shard, telemetry rounds
 
@@ -83,4 +84,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

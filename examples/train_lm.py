@@ -14,6 +14,7 @@ from repro.data.pipeline import AerialPipeline, PipelineConfig
 from repro.models.model import Model
 from repro.train import checkpoint as ckpt
 from repro.train import optimizer as optlib
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -57,9 +57,10 @@ from repro.api import AerialDB, AggSpec, Query, StoreConfig
 from repro.data.synthetic import DroneFleet
 from repro.launch.mesh import make_edge_mesh, make_fleet_mesh
 
-# "Compiling <name> with global shapes and types ..." — emitted by the
+# "Compiling jit(<name>) with global shapes and types ..." — emitted by the
 # dispatch/pxla layer once per jit cache miss when jax_log_compiles is on.
-_COMPILE_RE = re.compile(r"Compiling ([^\s]+) with global shapes")
+# The captured group is the bare entry-point name the budget table keys on.
+_COMPILE_RE = re.compile(r"Compiling jit\(([^\s()]+)\) with global shapes")
 _JAX_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
 
 

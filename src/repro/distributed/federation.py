@@ -53,7 +53,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -181,11 +180,11 @@ def _insert_fn(cfg: StoreConfig, mesh: Mesh):
         return insert_local(cfg, state, payload, meta, alive, edge_ids,
                             collectives=collectives)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_specs, P(), meta_specs, P(), P(axes)),
         out_specs=(state_specs, _insert_info_specs(False, axes)),
-        check_rep=False)
+        check_vma=False)
 
     def step(state, payload, meta, alive):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
@@ -225,11 +224,11 @@ def _ingest_fn(cfg: StoreConfig, mesh: Optional[Mesh]):
 
     axes = mesh_edge_axes(mesh)
     state_specs = store_partition_specs(axes)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(state_specs, P(), meta_specs, P(), P(axes)),
         out_specs=(state_specs, _insert_info_specs(True, axes)),
-        check_rep=False)
+        check_vma=False)
 
     def multi(state, payloads, metas, alive):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
@@ -289,13 +288,13 @@ def _query_fn(cfg: StoreConfig, mesh: Mesh, use_kernel: bool,
 
     def outer(state, pred, alive, key_data):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=mesh,
             in_specs=(state_specs, _replicated_like(pred), P(), P(),
                       P(axes)),
             out_specs=(partial_specs, P(None, axes),
                        (P(),) * 6),
-            check_rep=False)
+            check_vma=False)
         partials, sublist_len, meta_info = \
             sharded(state, pred, alive, key_data, edge_ids)
         # The only tuple-volume-independent cross-device reduction: the final

@@ -65,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "block_q", "block_k", "interpret"))
 def flash_attention_kernel(q, k, v, *, group: int = 1, causal: bool = True,
                            q_offset: int = 0, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool = False):
     """q: (N, Sq, d) with N = B*H_q; k/v: (N // group, Skv, d)."""
     n, sq, d = q.shape
     skv = k.shape[1]

@@ -1,5 +1,5 @@
 """Jit'd wrapper: model-layout (B, S, H, dh) GQA attention on the Pallas
-flash kernel (interpret on CPU, native on TPU)."""
+flash kernel (compiled unless interpret=True)."""
 
 from __future__ import annotations
 
@@ -8,7 +8,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_kernel
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, q_offset: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Drop-in for models.attention.flash_attention (same layout/semantics)."""
     b, sq, h, dh = q.shape
     kv = k.shape[2]

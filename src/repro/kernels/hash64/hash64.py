@@ -26,7 +26,7 @@ def _kernel(hi_ref, lo_ref, out_hi_ref, out_lo_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def xxh64(hi: jnp.ndarray, lo: jnp.ndarray, block: int = 1024,
-          interpret: bool = True):
+          interpret: bool = False):
     """Batched xxHash64 over (hi, lo) uint32 limb arrays of shape (N,)."""
     n = hi.shape[0]
     pad = (-n) % block

@@ -6,7 +6,10 @@ performs **no relayout**: the only data movement before the kernel is
 constant padding — the tuple axis to a ``block_c`` multiple (a no-op for
 lane-aligned store capacities), the query axis to a ``block_q`` multiple
 (padding queries carry ``sublist_len == 0`` so they match nothing and are
-sliced off the outputs), and the OR-list axis to a lane multiple.
+sliced off the outputs), and the OR-list axis to the kernel's entry-chunk
+multiple. The per-(query, edge) operands and results are handed to the
+kernel edge-major and transposed back to the ``(Q, E)`` / ``(Q, K, E)``
+shapes ``scan_engine`` returns.
 ``interpret=None`` (the default) auto-selects: compiled execution on TPU,
 interpret mode elsewhere (CPU tests / this container).
 """
@@ -20,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.st_scan.ref import check_channels
-from repro.kernels.st_scan.st_scan import st_scan_kernel
+from repro.kernels.st_scan.st_scan import L_CHUNK, st_scan_kernel
 
 
 def pack_pred(pred):
@@ -83,16 +86,21 @@ def st_scan(tup_f, tup_sid, tup_count, pred, sublists, sublist_len,
         sublists = jnp.pad(sublists, ((0, pad_q), (0, 0), (0, 0), (0, 0)),
                            constant_values=-(1 << 30))
         sublist_len = jnp.pad(sublist_len, ((0, pad_q), (0, 0)))
-    # Pad the OR-list length to a lane multiple.
+    # Pad the OR-list length to the kernel's entry-chunk multiple (entries
+    # past sublist_len never match, so the padding value is irrelevant).
     l = sublists.shape[2]
-    pad_l = (-l) % 128
+    pad_l = (-l) % L_CHUNK
     if pad_l:
         sublists = jnp.pad(sublists, ((0, 0), (0, 0), (0, pad_l), (0, 0)),
                            constant_values=-(1 << 30))
+    # Kernel layout: per-edge operands and results are edge-major with a
+    # trailing unit dim, so no block puts the edge axis in its last two dims.
     count, vsum, vmin, vmax = st_scan_kernel(
-        tup_f, tup_sid, tup_count[:, None], pred_f, pred_i, sublists,
-        sublist_len, block_c=block_c, block_q=block_q, interpret=interpret,
-        valid_c=min(valid_c, c), value_cols=value_cols)
-    if pad_q:
-        count, vsum, vmin, vmax = (count[:q], vsum[:q], vmin[:q], vmax[:q])
+        tup_f, tup_sid, tup_count.astype(jnp.int32), pred_f, pred_i,
+        sublists, sublist_len.T[:, :, None], block_c=block_c,
+        block_q=block_q, interpret=interpret, valid_c=min(valid_c, c),
+        value_cols=value_cols)
+    count = count[:, :q, 0].T                                  # (Q, E)
+    vsum, vmin, vmax = (a[:, :, :q, 0].transpose(2, 1, 0)     # (Q, K, E)
+                        for a in (vsum, vmin, vmax))
     return count, vsum, vmin, vmax

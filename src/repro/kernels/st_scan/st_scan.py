@@ -14,17 +14,25 @@ TPU-native layout decisions (vs the paper's row-store in InfluxDB):
     (128-aligned by ``init_store``'s capacity padding), giving unit-stride
     vector loads per field with no per-query relayout;
   * queries are tiled: the predicate is a (block_q, block_c) broadcast
-    evaluation and the shard OR-list membership a (block_q, L, block_c)
-    broadcast-compare, so each resident VMEM tuple tile answers block_q
-    queries before the grid advances — HBM tuple traffic is
-    ceil(Q/block_q)x the log instead of Qx;
+    evaluation, so each resident VMEM tuple tile answers block_q queries
+    before the grid advances — HBM tuple traffic is ceil(Q/block_q)x the
+    log instead of Qx;
+  * the shard OR-list keeps its (L, 2) entries on the sublane axis, so each
+    query's membership test is an (l_chunk, block_c) compare of list
+    columns against the tile's sid rows, OR-folded over ``l_chunk``-entry
+    chunks — the working set stays a few dozen vregs whatever L is;
   * aggregation is fused across channels: one predicate mask drives the
     count and every requested channel's sum/min/max accumulators
     (the marginal cost per extra channel is one VMEM row already resident
     in the tuple tile);
-  * accumulators are (block_q, 1) / (block_q, K, 1) output tiles revisited
-    across the c-grid (Pallas revisiting-output pattern), so no cross-block
-    reduction pass.
+  * accumulators are (1, block_q, 1) / (1, K, block_q, 1) output tiles of
+    edge-major ``(E, Q, 1)`` / ``(E, K, Q, 1)`` arrays, revisited across the
+    c-grid (Pallas revisiting-output pattern), so no cross-block reduction
+    pass. The edge axis is leading, never one of the last two block dims,
+    which is what Mosaic's (8, 128) block rule requires.
+
+The ring-buffer counter ``tup_count`` is a scalar-prefetch operand (SMEM):
+one int per edge, read as a scalar for the validity bound.
 
 Grid note: the grid is ``(E, Q // block_q, C // block_c)`` with the c axis
 FASTEST — each (edge, query-tile) accumulator is completed over consecutive
@@ -40,11 +48,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# OR-list entries compared per inner-loop step: an (L_CHUNK, block_c) int32
+# compare is 16 vregs at block_c = 512.
+L_CHUNK = 32
 
 
-def _kernel(tupf_ref, sidl_ref, cnt_ref, predf_ref, predi_ref, subl_ref,
-            slen_ref, count_ref, vsum_ref, vmin_ref, vmax_ref, *, block_c: int,
-            valid_c: int, value_cols: tuple):
+def _kernel(cnt_ref, tupf_ref, sidl_ref, predf_ref, predi_ref, subl_ref,
+            slen_ref, count_ref, vsum_ref, vmin_ref, vmax_ref, *,
+            block_c: int, valid_c: int, value_cols: tuple):
+    pe = pl.program_id(0)
     pc = pl.program_id(2)
 
     @pl.when(pc == 0)
@@ -63,7 +77,7 @@ def _kernel(tupf_ref, sidl_ref, cnt_ref, predf_ref, predi_ref, subl_ref,
     # Ring-buffer validity: slots below min(count, valid_c) are live, where
     # valid_c is the LOGICAL ring capacity — a monotonic total-written count
     # above capacity must never admit lane-padding slots.
-    n_valid = jnp.minimum(cnt_ref[0, 0], valid_c)
+    n_valid = jnp.minimum(cnt_ref[pe], valid_c)
     base = pc * block_c
     idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)
     alive = idx < n_valid        # (1, BC)
@@ -77,55 +91,71 @@ def _kernel(tupf_ref, sidl_ref, cnt_ref, predf_ref, predi_ref, subl_ref,
     hs, ht, hi = pi[:, 2:3] != 0, pi[:, 3:4] != 0, pi[:, 4:5] != 0
     m_and = (sp | ~hs) & (tp | ~ht) & (ip | ~hi)
     m_or = (sp & hs) | (tp & ht) | (ip & hi)
-    pm = jnp.where(pi[:, 5:6] != 0, m_and, m_or)              # (BQ, BC)
+    is_and = pi[:, 5:6] != 0      # bool selects as logic: Mosaic has no i1 select
+    pm = (is_and & m_and) | (~is_and & m_or)                  # (BQ, BC)
 
-    # Shard OR-list membership: (BQ, L, BC) broadcast compare.
-    slen = slen_ref[...]                                      # (BQ, 1)
-    l = subl_ref.shape[2]
-    list_hi = subl_ref[:, 0, :, 0]                            # (BQ, L)
-    list_lo = subl_ref[:, 0, :, 1]
-    k = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1)
-    entry_ok = k < jnp.abs(slen)                              # (BQ, L)
-    hit = (sid_hi[:, None, :] == list_hi[:, :, None]) & \
-          (sid_lo[:, None, :] == list_lo[:, :, None]) & entry_ok[:, :, None]
-    in_list = jnp.any(hit, axis=1)                            # (BQ, BC)
-    shard_ok = jnp.where(slen < 0, True, in_list) & (slen != 0)
+    # Shard OR-list membership, one query row at a time: list entries sit on
+    # sublanes, tuple sids on lanes; entries at or past |sublist_len| never
+    # match.
+    slen = slen_ref[0]                                        # (BQ, 1)
+    block_q = slen.shape[0]
+    n_chunks = subl_ref.shape[2] // L_CHUNK
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    entry = jax.lax.broadcasted_iota(jnp.int32, (L_CHUNK, 1), 0)
+    in_list = jnp.zeros((block_q, block_c), jnp.bool_)
+    for qi in range(block_q):
+        n_entries = jnp.abs(slen[qi:qi + 1, :])                # (1, 1)
+
+        def chunk(j, acc, qi=qi, n_entries=n_entries):
+            k0 = pl.multiple_of(j * L_CHUNK, L_CHUNK)
+            l_hi = subl_ref[qi, 0, pl.ds(k0, L_CHUNK), 0:1]   # (LC, 1)
+            l_lo = subl_ref[qi, 0, pl.ds(k0, L_CHUNK), 1:2]
+            ok = (k0 + entry) < n_entries                     # (LC, 1)
+            hit = (sid_hi == l_hi) & (sid_lo == l_lo) & ok    # (LC, BC)
+            return acc | hit.astype(jnp.int32)
+
+        acc = jax.lax.fori_loop(0, n_chunks, chunk,
+                                jnp.zeros((L_CHUNK, block_c), jnp.int32))
+        hit_q = jnp.max(acc, axis=0, keepdims=True) > 0       # (1, BC)
+        in_list = in_list | ((rows == qi) & hit_q)
+    shard_ok = ((slen < 0) | in_list) & (slen != 0)
 
     m = pm & shard_ok & alive                                 # (BQ, BC)
-    count_ref[...] += jnp.sum(m, axis=1, keepdims=True).astype(jnp.int32)
+    count_ref[0] += jnp.sum(m.astype(jnp.int32), axis=1, keepdims=True)
     # Fused multi-channel aggregation: the mask is computed once; every
     # requested channel's row is already resident in the VMEM tuple tile.
     for kk, col in enumerate(value_cols):
         v = tupf_ref[0, col:col + 1, :]                       # (1, BC)
-        vsum_ref[:, kk] += jnp.sum(jnp.where(m, v, 0.0), axis=1, keepdims=True)
-        vmin_ref[:, kk] = jnp.minimum(
-            vmin_ref[:, kk],
+        vsum_ref[0, kk] += jnp.sum(jnp.where(m, v, 0.0), axis=1, keepdims=True)
+        vmin_ref[0, kk] = jnp.minimum(
+            vmin_ref[0, kk],
             jnp.min(jnp.where(m, v, jnp.inf), axis=1, keepdims=True))
-        vmax_ref[:, kk] = jnp.maximum(
-            vmax_ref[:, kk],
+        vmax_ref[0, kk] = jnp.maximum(
+            vmax_ref[0, kk],
             jnp.max(jnp.where(m, v, -jnp.inf), axis=1, keepdims=True))
 
 
-def st_scan_kernel(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists_t,
-                   sublist_len, *, block_c: int = 512, block_q: int = 8,
-                   interpret: "bool | None" = None,
+def st_scan_kernel(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists,
+                   slen_t, *, block_c: int = 512, block_q: int = 8,
+                   interpret: bool = False,
                    valid_c: "int | None" = None,
                    value_cols: "tuple[int, ...]" = (3,)):
-    """Invoke the Pallas scan.
+    """Invoke the Pallas scan on kernel-layout operands (``ops.st_scan``
+    adapts the engine's ``(Q, E)`` layout to these).
 
     Args:
       tupf_t:      (E, W, C) float32 column-major tuple log (W >= 4).
       sid_t:       (E, 2, C) int32 shard ids.
-      tup_count:   (E, 1) int32 — ring-buffer total-written counter; clamped
-                   in-kernel to min(count, valid_c).
-      pred_f:      (Q, 8) float32 packed predicate; Q % block_q == 0
-                   (ops.py pads the query batch).
+      tup_count:   (E,) int32 — ring-buffer total-written counter; clamped
+                   in-kernel to min(count, valid_c). Scalar-prefetched.
+      pred_f:      (Q, 8) float32 packed predicate; Q % block_q == 0.
       pred_i:      (Q, 8) int32 packed predicate.
-      sublists_t:  (Q, E, L, 2) int32 OR-lists.
-      sublist_len: (Q, E) int32.
+      sublists:    (Q, E, L, 2) int32 OR-lists; L % L_CHUNK == 0.
+      slen_t:      (E, Q, 1) int32 OR-list lengths (edge-major).
       block_q:     queries evaluated per resident tuple tile — the HBM
-                   tuple-traffic divisor for batched queries.
-      interpret:   None = auto (compiled on TPU, interpreted elsewhere).
+                   tuple-traffic divisor for batched queries. Compiled
+                   kernels need a multiple of 8 (sublane tiling).
+      interpret:   run the Pallas interpreter instead of compiling.
       valid_c:     logical ring capacity (ops.py forwards the store's
                    un-lane-padded capacity so padding lanes are never
                    admitted); None = C.
@@ -133,11 +163,9 @@ def st_scan_kernel(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists_t,
                    selected sensor channels; 3 = v0). All are accumulated in
                    the same sweep.
 
-    Returns (count, vsum, vmin, vmax): count (Q, E) int32; the rest
-    (Q, K, E) float32 with K = len(value_cols).
+    Returns (count, vsum, vmin, vmax), edge-major: count (E, Q, 1) int32;
+    the rest (E, K, Q, 1) float32 with K = len(value_cols).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     e, w, c = tupf_t.shape
     if valid_c is None:
         valid_c = c
@@ -148,40 +176,52 @@ def st_scan_kernel(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists_t,
                 f"value_col={col} out of range: the column-major log has "
                 f"rows 0..2 = (t, lat, lon) and value rows 3..{w - 1}.")
     q = pred_f.shape[0]
-    l = sublists_t.shape[2]
+    l = sublists.shape[2]
     if c % block_c:
         raise ValueError(f"C={c} must be a multiple of block_c={block_c}")
     if q % block_q:
         raise ValueError(f"Q={q} must be a multiple of block_q={block_q} "
                          "(ops.py pads the query batch)")
+    if l % L_CHUNK:
+        raise ValueError(f"L={l} must be a multiple of {L_CHUNK} "
+                         "(ops.py pads the OR-lists)")
     grid = (e, q // block_q, c // block_c)
 
     kernel = functools.partial(_kernel, block_c=block_c, valid_c=valid_c,
                                value_cols=tuple(value_cols))
+    acc_spec = pl.BlockSpec((1, n_ch, block_q, 1),
+                            lambda e_, q_, c_, cnt: (e_, 0, q_, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, w, block_c), lambda e_, q_, c_: (e_, 0, c_)),
-            pl.BlockSpec((1, 2, block_c), lambda e_, q_, c_: (e_, 0, c_)),
-            pl.BlockSpec((1, 1), lambda e_, q_, c_: (e_, 0)),
-            pl.BlockSpec((block_q, 8), lambda e_, q_, c_: (q_, 0)),
-            pl.BlockSpec((block_q, 8), lambda e_, q_, c_: (q_, 0)),
-            pl.BlockSpec((block_q, 1, l, 2), lambda e_, q_, c_: (q_, e_, 0, 0)),
-            pl.BlockSpec((block_q, 1), lambda e_, q_, c_: (q_, e_)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, 1), lambda e_, q_, c_: (q_, e_)),
-            pl.BlockSpec((block_q, n_ch, 1), lambda e_, q_, c_: (q_, 0, e_)),
-            pl.BlockSpec((block_q, n_ch, 1), lambda e_, q_, c_: (q_, 0, e_)),
-            pl.BlockSpec((block_q, n_ch, 1), lambda e_, q_, c_: (q_, 0, e_)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, w, block_c),
+                             lambda e_, q_, c_, cnt: (e_, 0, c_)),
+                pl.BlockSpec((1, 2, block_c),
+                             lambda e_, q_, c_, cnt: (e_, 0, c_)),
+                pl.BlockSpec((block_q, 8), lambda e_, q_, c_, cnt: (q_, 0)),
+                pl.BlockSpec((block_q, 8), lambda e_, q_, c_, cnt: (q_, 0)),
+                pl.BlockSpec((block_q, 1, l, 2),
+                             lambda e_, q_, c_, cnt: (q_, e_, 0, 0)),
+                pl.BlockSpec((1, block_q, 1),
+                             lambda e_, q_, c_, cnt: (e_, q_, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, 1),
+                             lambda e_, q_, c_, cnt: (e_, q_, 0)),
+                acc_spec, acc_spec, acc_spec,
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((q, e), jnp.int32),
-            jax.ShapeDtypeStruct((q, n_ch, e), jnp.float32),
-            jax.ShapeDtypeStruct((q, n_ch, e), jnp.float32),
-            jax.ShapeDtypeStruct((q, n_ch, e), jnp.float32),
+            jax.ShapeDtypeStruct((e, q, 1), jnp.int32),
+            jax.ShapeDtypeStruct((e, n_ch, q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((e, n_ch, q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((e, n_ch, q, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists_t, sublist_len)
+    )(tup_count, tupf_t, sid_t, pred_f, pred_i, sublists, slen_t)
     return tuple(out)

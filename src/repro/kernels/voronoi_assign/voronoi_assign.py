@@ -30,7 +30,7 @@ def _kernel(pts_ref, sites_ref, snorm_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
 def voronoi_assign(points: jnp.ndarray, sites: jnp.ndarray,
-                   block_p: int = 1024, interpret: bool = True) -> jnp.ndarray:
+                   block_p: int = 1024, interpret: bool = False) -> jnp.ndarray:
     """(N, 2) float points x (E, 2) sites -> (N,) int32 nearest site."""
     n = points.shape[0]
     e = sites.shape[0]

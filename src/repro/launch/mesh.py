@@ -93,10 +93,7 @@ def init_fleet_processes(coordinator_address: str, num_processes: int,
     cross-process collectives need the gloo transport, which is selected
     here; real TPU/GPU backends ignore that knob and use their native
     fabric."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # older/newer jax without the knob
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

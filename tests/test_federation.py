@@ -433,7 +433,10 @@ def test_store_sharding_layout(mesh):
     axes = mesh_edge_axes(mesh)
     assert mesh_edge_devices(mesh) == N_DEV
     specs = store_partition_specs(axes)
-    assert specs.tup_f[0] == axes  # leading E dim over the axis product
+    # Leading E dim over the axis product. PartitionSpec stores a one-axis
+    # tuple as the bare axis name, so normalise before comparing.
+    lead = specs.tup_f[0]
+    assert (lead if isinstance(lead, tuple) else (lead,)) == axes
 
 
 def test_partition_specs_congruent_with_state(mesh):
