@@ -71,7 +71,7 @@ class CompileCounter(logging.Handler):
 
         with CompileCounter() as cc:
             run_workload()
-        assert cc.counts["outer"] == 2
+        assert cc.counts["fed_query"] == 2
 
     ``counts`` maps jaxpr entry-point name -> number of compilations
     observed inside the ``with`` block (a ``collections.Counter``).

@@ -61,6 +61,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.query import Query
 from repro.core import datastore as _ds
@@ -97,6 +98,7 @@ class AerialDB:
         self._use_kernel = use_kernel
         self._interpret = interpret
         self._last_repair: Optional[dict] = None
+        self._n_queries = 0      # tags each ``aerialdb.query`` span
         # Outage-epoch ledger (see ``core.repair``): open records are
         # in-flight outages ``[dead edge set, fail_step]``; closed records
         # ``(recovered edge set, fail_step, recover_step)`` accumulate until
@@ -229,31 +231,33 @@ class AerialDB:
     def insert(self, payload, meta: ShardMeta) -> dict:
         """Insert one batch of B shards (R tuples each); returns the info
         dict (replicas, per-edge intake/index telemetry)."""
-        payload = jnp.asarray(payload)
-        sid_hi = np.asarray(meta.sid_hi)[None]       # host copies of INPUTS —
-        sid_lo = np.asarray(meta.sid_lo)[None]       # no device-sync hazard
-        meta = ShardMeta(*[jnp.asarray(f) for f in meta])
-        mask = self.effective_alive
-        if self._mesh is None:
-            self._state, info = _ds._insert(self._cfg, self._state, payload,
-                                            meta, mask)
-        else:
-            self._state, info = _fed.federated_insert_step(
-                self._cfg, self._state, payload, meta, mask, self._mesh)
-        self._watch_drops(sid_hi, sid_lo,
-                          info["index_entries_dropped"][None])
-        return info
+        with TraceAnnotation("aerialdb.insert"):
+            payload = jnp.asarray(payload)
+            sid_hi = np.asarray(meta.sid_hi)[None]   # host copies of INPUTS
+            sid_lo = np.asarray(meta.sid_lo)[None]   # — no device-sync hazard
+            meta = ShardMeta(*[jnp.asarray(f) for f in meta])
+            mask = self.effective_alive
+            if self._mesh is None:
+                self._state, info = _ds._insert(self._cfg, self._state,
+                                                payload, meta, mask)
+            else:
+                self._state, info = _fed.federated_insert_step(
+                    self._cfg, self._state, payload, meta, mask, self._mesh)
+            self._watch_drops(sid_hi, sid_lo,
+                              info["index_entries_dropped"][None])
+            return info
 
     def ingest_rounds(self, payloads, metas) -> dict:
         """Fused multi-round ingest (one ``lax.scan`` dispatch, donated
         state); returns the info dict stacked over rounds."""
-        sid_hi = np.asarray(metas.sid_hi)            # (N, B) host copies
-        sid_lo = np.asarray(metas.sid_lo)
-        self._state, info = _fed.ingest_rounds(
-            self._cfg, self._state, payloads, metas, self.effective_alive,
-            mesh=self._mesh)
-        self._watch_drops(sid_hi, sid_lo, info["index_entries_dropped"])
-        return info
+        with TraceAnnotation("aerialdb.ingest_rounds"):
+            sid_hi = np.asarray(metas.sid_hi)        # (N, B) host copies
+            sid_lo = np.asarray(metas.sid_lo)
+            self._state, info = _fed.ingest_rounds(
+                self._cfg, self._state, payloads, metas,
+                self.effective_alive, mesh=self._mesh)
+            self._watch_drops(sid_hi, sid_lo, info["index_entries_dropped"])
+            return info
 
     # -- query --------------------------------------------------------------
 
@@ -297,24 +301,34 @@ class AerialDB:
         aggregates with ``result.view(agg_spec)``. A ``Query().latest()``
         builder short-circuits to :meth:`latest` and returns its
         ``LatestResult`` directly (no scan, no planner, no ``QueryInfo``).
+
+        Profiler spans: ``aerialdb.query`` (tagged ``q=<n>``, the session's
+        query count) around ``aerialdb.query.prepare`` (builder, AggSpec
+        check, key split, alive mask) and ``aerialdb.query.dispatch`` (the
+        jitted call until it returns, before the answer is ready).
         """
-        if isinstance(q, Query) and q.want_latest:
-            if agg is not None:
-                raise ValueError(
-                    "latest() queries take no AggSpec: the hot-cache read "
-                    "returns raw (D, 3+V) records, not aggregates.")
-            return self.latest()
-        pred, spec = self._compile(q, agg)
-        spec.validate_for(self._cfg)
-        if key is None:
-            self._key, key = jax.random.split(self._key)
-        mask = self.effective_alive
-        if self._mesh is None:
-            return _ds._query(self._cfg, self._state, pred, mask, key,
-                              self._use_kernel, self._interpret, spec)
-        return _fed.federated_query_step(
-            self._cfg, self._state, pred, mask, key, self._mesh,
-            use_kernel=self._use_kernel, interpret=self._interpret, agg=spec)
+        self._n_queries += 1
+        with TraceAnnotation("aerialdb.query", q=self._n_queries):
+            if isinstance(q, Query) and q.want_latest:
+                if agg is not None:
+                    raise ValueError(
+                        "latest() queries take no AggSpec: the hot-cache "
+                        "read returns raw (D, 3+V) records, not aggregates.")
+                return self.latest()
+            with TraceAnnotation("aerialdb.query.prepare"):
+                pred, spec = self._compile(q, agg)
+                spec.validate_for(self._cfg)
+                if key is None:
+                    self._key, key = jax.random.split(self._key)
+                mask = self.effective_alive
+            with TraceAnnotation("aerialdb.query.dispatch"):
+                if self._mesh is None:
+                    return _ds._query(self._cfg, self._state, pred, mask, key,
+                                      self._use_kernel, self._interpret, spec)
+                return _fed.federated_query_step(
+                    self._cfg, self._state, pred, mask, key, self._mesh,
+                    use_kernel=self._use_kernel, interpret=self._interpret,
+                    agg=spec)
 
     def latest(self) -> LatestResult:
         """Latest-per-drone hot-cache read (paper §4.4 near-real-time path):

@@ -66,6 +66,15 @@ still holds; loss is bounded by the replicas' retention skew.
 The per-edge query engine (the paper's InfluxDB role) is a predicate scan —
 ``repro.kernels.st_scan`` provides the Pallas TPU kernel; ``scan_engine`` here
 dispatches to it or to the jnp reference.
+
+Phase names: every phase of ``query_local`` (``query.lookup``,
+``query.merge``, ``query.plan``, ``query.orlist``, ``query.scan``),
+``finalize_query`` (``query.combine``) and ``insert_local``
+(``insert.place``, ``insert.ring``, ``insert.retire``, ``insert.index``,
+``insert.latest``) runs under a ``jax.named_scope``. A scope only labels the
+``op_name`` metadata of the HLO it emits, so a profiler trace attributes
+device time to phases; the compiled programs and their answers are those of
+the unlabelled code.
 """
 
 from __future__ import annotations
@@ -78,6 +87,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import hashing, planner as planner_lib
 from repro.core.index import (IndexState, QueryPred, compact_index,
@@ -446,21 +456,22 @@ def make_pred(q: int = 1, lat0=0.0, lat1=0.0, lon0=0.0, lon1=0.0, t0=0.0,
     nothing. The ``repro.api.Query`` builder performs the same validation
     eagerly (for every clause, since the builder composes clause-wise).
     """
-    _check_ranges(q, [("lat", lat0, lat1), ("lon", lon0, lon1)],
-                  has_spatial, is_and)
-    _check_ranges(q, [("t", t0, t1)], has_temporal, is_and)
-
     def arr(x, dt):
         a = jnp.asarray(x, dt)
         return jnp.broadcast_to(a, (q,) if a.ndim == 0 else a.shape)
-    return QueryPred(
-        lat0=arr(lat0, jnp.float32), lat1=arr(lat1, jnp.float32),
-        lon0=arr(lon0, jnp.float32), lon1=arr(lon1, jnp.float32),
-        t0=arr(t0, jnp.float32), t1=arr(t1, jnp.float32),
-        sid_hi=arr(sid_hi, jnp.int32), sid_lo=arr(sid_lo, jnp.int32),
-        has_spatial=arr(has_spatial, jnp.bool_),
-        has_temporal=arr(has_temporal, jnp.bool_),
-        has_sid=arr(has_sid, jnp.bool_), is_and=arr(is_and, jnp.bool_))
+
+    with TraceAnnotation("aerialdb.make_pred"):
+        _check_ranges(q, [("lat", lat0, lat1), ("lon", lon0, lon1)],
+                      has_spatial, is_and)
+        _check_ranges(q, [("t", t0, t1)], has_temporal, is_and)
+        return QueryPred(
+            lat0=arr(lat0, jnp.float32), lat1=arr(lat1, jnp.float32),
+            lon0=arr(lon0, jnp.float32), lon1=arr(lon1, jnp.float32),
+            t0=arr(t0, jnp.float32), t1=arr(t1, jnp.float32),
+            sid_hi=arr(sid_hi, jnp.int32), sid_lo=arr(sid_lo, jnp.int32),
+            has_spatial=arr(has_spatial, jnp.bool_),
+            has_temporal=arr(has_temporal, jnp.bool_),
+            has_sid=arr(has_sid, jnp.bool_), is_and=arr(is_and, jnp.bool_))
 
 
 def init_store(cfg: StoreConfig) -> StoreState:
@@ -563,45 +574,48 @@ def insert_local(cfg: StoreConfig, state: StoreState, payload: jnp.ndarray,
     b, r, w = payload.shape
     sites = cfg.sites_array()
 
-    replicas = place_replicas(meta, sites, alive, cfg.tau,
-                              n_domains=cfg.n_failure_domains)  # (B, 3)
-    replicas = replicas[:, : cfg.replication]
-    alive_loc = jnp.take(alive, edge_ids)
+    with jax.named_scope("insert.place"):
+        replicas = place_replicas(meta, sites, alive, cfg.tau,
+                                  n_domains=cfg.n_failure_domains)  # (B, 3)
+        replicas = replicas[:, : cfg.replication]
+        alive_loc = jnp.take(alive, edge_ids)
+        # --- tuple dispatch: one-hot shard->edge routing (MoE-style) ---
+        dm = jnp.any(replicas[..., None] == edge_ids, axis=1)    # (B, E_loc)
+        dm = dm & alive_loc[None, :]
 
-    # --- tuple dispatch: one-hot shard->edge routing (MoE-style) ---
-    dm = jnp.any(replicas[..., None] == edge_ids, axis=1)        # (B, E_loc)
-    dm = dm & alive_loc[None, :]
-    rank = jnp.cumsum(dm, axis=0) - 1                            # (B, E_loc)
-    start = state.tup_pos[None, :] + rank * r                    # (B, E_loc)
-    pos = start[..., None] + jnp.arange(r, dtype=jnp.int32)      # (B, E_loc, R)
-    ok = dm[..., None]
-    # Ring slot modulo the LOGICAL capacity (lane-padding slots stay dead);
-    # the drop sentinel must be out of range of the PADDED tuple axis.
-    pp = jnp.where(ok, pos % cap, cfg.padded_capacity)
-    ee = jnp.broadcast_to(
-        jnp.arange(e_loc, dtype=jnp.int32)[None, :, None], (b, e_loc, r))
+    with jax.named_scope("insert.ring"):
+        rank = jnp.cumsum(dm, axis=0) - 1                        # (B, E_loc)
+        start = state.tup_pos[None, :] + rank * r                # (B, E_loc)
+        pos = start[..., None] + jnp.arange(r, dtype=jnp.int32)  # (B,E_loc,R)
+        ok = dm[..., None]
+        # Ring slot modulo the LOGICAL capacity (lane-padding slots stay
+        # dead); the drop sentinel must be out of range of the PADDED tuple
+        # axis.
+        pp = jnp.where(ok, pos % cap, cfg.padded_capacity)
+        ee = jnp.broadcast_to(
+            jnp.arange(e_loc, dtype=jnp.int32)[None, :, None], (b, e_loc, r))
 
-    pay = jnp.broadcast_to(payload[:, None], (b, e_loc, r, w))
-    sid = jnp.broadcast_to(
-        jnp.stack([meta.sid_hi, meta.sid_lo], axis=-1)[:, None, None, :],
-        (b, e_loc, r, 2))
+        pay = jnp.broadcast_to(payload[:, None], (b, e_loc, r, w))
+        sid = jnp.broadcast_to(
+            jnp.stack([meta.sid_hi, meta.sid_lo], axis=-1)[:, None, None, :],
+            (b, e_loc, r, 2))
 
-    # Column-major write pattern: one scatter per tuple writes its whole
-    # field COLUMN tup_f[e, :, slot] (the slice between the advanced indices
-    # spans the field rows), so the lane-aligned log never needs a
-    # query-time relayout.
-    tup_f = state.tup_f.at[ee, :, pp].set(pay, mode="drop")
-    tup_sid = state.tup_sid.at[ee, :, pp].set(sid, mode="drop")
-    n_in = jnp.sum(dm, axis=0) * r                               # (E_loc,)
-    tup_pos = ((state.tup_pos + n_in) % cap).astype(jnp.int32)
-    tup_count = jnp.minimum(state.tup_count + n_in,
-                            _COUNT_SAT).astype(jnp.int32)        # monotonic
-    # Retention telemetry: slots reclaimed from the previous window.
-    valid_before = jnp.minimum(state.tup_count, cap)
-    valid_after = jnp.minimum(tup_count, cap)
-    overwritten_now = (valid_before + n_in - valid_after).astype(jnp.int32)
-    tup_overwritten = jnp.minimum(state.tup_overwritten + overwritten_now,
-                                  _COUNT_SAT).astype(jnp.int32)
+        # Column-major write pattern: one scatter per tuple writes its whole
+        # field COLUMN tup_f[e, :, slot] (the slice between the advanced
+        # indices spans the field rows), so the lane-aligned log never needs
+        # a query-time relayout.
+        tup_f = state.tup_f.at[ee, :, pp].set(pay, mode="drop")
+        tup_sid = state.tup_sid.at[ee, :, pp].set(sid, mode="drop")
+        n_in = jnp.sum(dm, axis=0) * r                           # (E_loc,)
+        tup_pos = ((state.tup_pos + n_in) % cap).astype(jnp.int32)
+        tup_count = jnp.minimum(state.tup_count + n_in,
+                                _COUNT_SAT).astype(jnp.int32)    # monotonic
+        # Retention telemetry: slots reclaimed from the previous window.
+        valid_before = jnp.minimum(state.tup_count, cap)
+        valid_after = jnp.minimum(tup_count, cap)
+        overwritten_now = (valid_before + n_in - valid_after).astype(jnp.int32)
+        tup_overwritten = jnp.minimum(state.tup_overwritten + overwritten_now,
+                                      _COUNT_SAT).astype(jnp.int32)
 
     # --- index retention (cadenced): retire entries whose data has aged out
     # of every replica edge's ring, then compact so the cursor is reusable.
@@ -614,9 +628,6 @@ def insert_local(cfg: StoreConfig, state: StoreState, payload: jnp.ndarray,
     # OUTSIDE the cond so
     # every device executes the same collective schedule regardless of how
     # rep-checking handles conditional branches. ---
-    steps = state.steps + 1
-    do_sweep = steps % cfg.retention_every == 0
-
     def _local_wm(_):
         retained = (jnp.arange(cfg.padded_capacity, dtype=jnp.int32)[None, :]
                     < valid_after[:, None])                      # (E_loc, CAP_L)
@@ -630,29 +641,36 @@ def insert_local(cfg: StoreConfig, state: StoreState, payload: jnp.ndarray,
         return jnp.where(lossy, t_oldest,
                          -jnp.inf).astype(jnp.float32)           # (E_loc,)
 
-    wm_local = jax.lax.cond(
-        do_sweep, _local_wm,
-        lambda _: jnp.full((e_loc,), -jnp.inf, jnp.float32), None)
-    watermark = collectives.gather_watermark(wm_local)           # (E,) global
-    index = jax.lax.cond(
-        do_sweep, lambda ix: compact_index(retire_entries(ix, watermark)),
-        lambda ix: ix, state.index)
+    with jax.named_scope("insert.retire"):
+        steps = state.steps + 1
+        do_sweep = steps % cfg.retention_every == 0
+        wm_local = jax.lax.cond(
+            do_sweep, _local_wm,
+            lambda _: jnp.full((e_loc,), -jnp.inf, jnp.float32), None)
+        watermark = collectives.gather_watermark(wm_local)       # (E,) global
+        index = jax.lax.cond(
+            do_sweep, lambda ix: compact_index(retire_entries(ix, watermark)),
+            lambda ix: ix, state.index)
 
     # --- sliced index entries (§3.4.3) ---
-    idx_mask = _index_edge_mask(cfg, meta, replicas, sites, alive)  # (B, E)
-    idx_mask = jnp.take(idx_mask, edge_ids, axis=1)                 # (B, E_loc)
-    index = insert_entries(index, meta,
-                           jnp.pad(replicas, ((0, 0), (0, 3 - cfg.replication)),
-                                   constant_values=-1),
-                           idx_mask, step=steps)
+    with jax.named_scope("insert.index"):
+        idx_mask = _index_edge_mask(cfg, meta, replicas, sites,
+                                    alive)                       # (B, E)
+        idx_mask = jnp.take(idx_mask, edge_ids, axis=1)          # (B, E_loc)
+        index = insert_entries(
+            index, meta,
+            jnp.pad(replicas, ((0, 0), (0, 3 - cfg.replication)),
+                    constant_values=-1),
+            idx_mask, step=steps)
 
     # --- latest-per-drone hot cache: replicated O(D) state, updated on the
     # ingest path from the same replicated payload (statically compiled out
     # when the cache is disabled so existing graphs are untouched). ---
     latest_f, latest_seen = state.latest_f, state.latest_seen
     if cfg.max_drones:
-        latest_f, latest_seen = _update_latest(
-            latest_f, latest_seen, payload, meta.sid_hi, steps)
+        with jax.named_scope("insert.latest"):
+            latest_f, latest_seen = _update_latest(
+                latest_f, latest_seen, payload, meta.sid_hi, steps)
 
     new_state = StoreState(index, tup_f, tup_sid, tup_count, tup_pos,
                            tup_overwritten, state.tup_dropped, steps,
@@ -881,83 +899,98 @@ def query_local(cfg: StoreConfig, state: StoreState, pred: QueryPred,
     e_loc = edge_ids.shape[0]
     sites = cfg.sites_array()
 
-    lookup_mask, broadcast = _lookup_sets(cfg, pred, sites, alive)   # (Q, E)
-    lookup_loc = jnp.take(lookup_mask, edge_ids, axis=1)             # (Q, E_loc)
+    with jax.named_scope("query.lookup"):
+        lookup_mask, broadcast = _lookup_sets(cfg, pred, sites,
+                                              alive)             # (Q, E)
+        lookup_loc = jnp.take(lookup_mask, edge_ids, axis=1)     # (Q, E_loc)
 
     if not cfg.use_index:
         # Broadcast baseline (Feather-like): no shard scoping; every alive
         # edge scans everything. StoreConfig rejects use_index=False with
         # replication > 1, which would overcount ~R-fold here. No candidate
         # merge means nothing to overlap — the batch stays untiled.
-        alive_loc = jnp.take(alive, edge_ids)
-        sublists = jnp.zeros((q, e_loc, 1, 2), jnp.int32)
-        sublist_len = jnp.where(jnp.broadcast_to(alive_loc, (q, e_loc)),
-                                -1, 0).astype(jnp.int32)
+        with jax.named_scope("query.orlist"):
+            alive_loc = jnp.take(alive, edge_ids)
+            sublists = jnp.zeros((q, e_loc, 1, 2), jnp.int32)
+            sublist_len = jnp.where(jnp.broadcast_to(alive_loc, (q, e_loc)),
+                                    -1, 0).astype(jnp.int32)
         ovf = jnp.zeros((q,), jnp.bool_)
         shards_matched = jnp.full((q,), -1, jnp.int32)
         # No index: no shard tracking, so completeness is unknowable here.
         replicas_lost = jnp.zeros((q,), jnp.int32)
         bound = jnp.full((q,), jnp.nan, jnp.float32)
-        partials = scan_engine(state.tup_f, state.tup_sid, state.tup_count,
-                               pred, sublists, sublist_len, use_kernel,
-                               interpret, channels=agg.channels,
-                               valid_c=cfg.tuple_capacity)
+        with jax.named_scope("query.scan"):
+            partials = scan_engine(state.tup_f, state.tup_sid,
+                                   state.tup_count, pred, sublists,
+                                   sublist_len, use_kernel, interpret,
+                                   channels=agg.channels,
+                                   valid_c=cfg.tuple_capacity)
         return partials, sublist_len, (lookup_mask, broadcast, ovf,
                                        shards_matched, replicas_lost, bound)
 
     # Per-query planner keys (key folded with the GLOBAL query index), so
     # planner randomness is invariant to the tiling below.
-    qkeys = jax.vmap(jax.random.fold_in, (None, 0))(key,
-                                                    jnp.arange(q))
+    with jax.named_scope("query.plan"):
+        qkeys = jax.vmap(jax.random.fold_in, (None, 0))(key, jnp.arange(q))
 
     # Phase 1 — index match + candidate merge for EVERY tile up front: all
     # cross-device exchanges are issued before any log scan.
     tiles = _tile_slices(q, overlap_tiles)
     pred_tiles = [jax.tree.map(lambda a: a[sl], pred) for sl in tiles]
-    matched_tiles = [
-        collectives.combine_matched(
-            lookup(state.index, p, lookup_loc[sl], s), s)
-        for sl, p in zip(tiles, pred_tiles)]
+    matched_tiles = []
+    for sl, p in zip(tiles, pred_tiles):
+        with jax.named_scope("query.lookup"):
+            local = lookup(state.index, p, lookup_loc[sl], s)
+        with jax.named_scope("query.merge"):
+            matched_tiles.append(collectives.combine_matched(local, s))
 
     # Phase 2 — plan + per-edge OR-lists + single-pass scan, per tile (tile
     # t's scan is dependency-free of tile t+1's in-flight merge).
     outs = []
     for sl, p, matched in zip(tiles, pred_tiles, matched_tiles):
         qt = p.lat0.shape[0]
-        assignment = planner_lib.plan(cfg.planner, matched, alive,
-                                      qkeys[sl])                  # (Qt, S)
-        # Per-edge OR-lists: rank of shard within its assigned edge.
-        am = (assignment[..., None] == edge_ids)                  # (Qt, S, E_loc)
-        rank = jnp.cumsum(am, axis=1) - 1
-        pos = jnp.where(am, rank, s)
-        sublists = jnp.full((qt, e_loc, s, 2), -1, jnp.int32)
-        qq = jnp.broadcast_to(jnp.arange(qt, dtype=jnp.int32)[:, None, None],
-                              (qt, s, e_loc))
-        ee = jnp.broadcast_to(jnp.arange(e_loc, dtype=jnp.int32)[None, None, :],
-                              (qt, s, e_loc))
-        sidv = jnp.stack([matched.sid_hi, matched.sid_lo], axis=-1)  # (Qt, S, 2)
-        sidv = jnp.broadcast_to(sidv[:, :, None, :], (qt, s, e_loc, 2))
-        sublists = sublists.at[qq, ee, pos].set(sidv, mode="drop")
-        sublist_len = jnp.sum(am, axis=1).astype(jnp.int32)       # (Qt, E_loc)
-        ovf = matched.overflow
-        shards_matched = jnp.sum(matched.valid, axis=-1)
-        # Degraded-query accounting (replicated metadata, like planning):
-        # dead replica slots over the matched set, and the planner-derived
-        # completeness bound — matched shards whose replicas all died are
-        # unassignable (assignment == -1) and provably missing from the
-        # result. Overflow clips the tracked set, so the bound is unknown.
-        reps = matched.replicas
-        dead_slot = (matched.valid[..., None] & (reps >= 0)
-                     & ~jnp.take(alive, jnp.clip(reps, 0), axis=0))
-        replicas_lost = jnp.sum(dead_slot, axis=(1, 2)).astype(jnp.int32)
-        assigned_n = jnp.sum(matched.valid & (assignment >= 0), axis=-1)
-        bound = jnp.where(shards_matched > 0,
-                          assigned_n / jnp.maximum(shards_matched, 1), 1.0)
-        bound = jnp.where(ovf, jnp.nan, bound).astype(jnp.float32)
-        partials = scan_engine(state.tup_f, state.tup_sid, state.tup_count,
-                               p, sublists, sublist_len, use_kernel,
-                               interpret, channels=agg.channels,
-                               valid_c=cfg.tuple_capacity)
+        with jax.named_scope("query.plan"):
+            assignment = planner_lib.plan(cfg.planner, matched, alive,
+                                          qkeys[sl])              # (Qt, S)
+        with jax.named_scope("query.orlist"):
+            # Per-edge OR-lists: rank of shard within its assigned edge.
+            am = (assignment[..., None] == edge_ids)          # (Qt, S, E_loc)
+            rank = jnp.cumsum(am, axis=1) - 1
+            pos = jnp.where(am, rank, s)
+            sublists = jnp.full((qt, e_loc, s, 2), -1, jnp.int32)
+            qq = jnp.broadcast_to(
+                jnp.arange(qt, dtype=jnp.int32)[:, None, None], (qt, s, e_loc))
+            ee = jnp.broadcast_to(
+                jnp.arange(e_loc, dtype=jnp.int32)[None, None, :],
+                (qt, s, e_loc))
+            sidv = jnp.stack([matched.sid_hi, matched.sid_lo],
+                             axis=-1)                             # (Qt, S, 2)
+            sidv = jnp.broadcast_to(sidv[:, :, None, :], (qt, s, e_loc, 2))
+            sublists = sublists.at[qq, ee, pos].set(sidv, mode="drop")
+            sublist_len = jnp.sum(am, axis=1).astype(jnp.int32)   # (Qt, E_loc)
+        with jax.named_scope("query.plan"):
+            ovf = matched.overflow
+            shards_matched = jnp.sum(matched.valid, axis=-1)
+            # Degraded-query accounting (replicated metadata, like
+            # planning): dead replica slots over the matched set, and the
+            # planner-derived completeness bound — matched shards whose
+            # replicas all died are unassignable (assignment == -1) and
+            # provably missing from the result. Overflow clips the tracked
+            # set, so the bound is unknown.
+            reps = matched.replicas
+            dead_slot = (matched.valid[..., None] & (reps >= 0)
+                         & ~jnp.take(alive, jnp.clip(reps, 0), axis=0))
+            replicas_lost = jnp.sum(dead_slot, axis=(1, 2)).astype(jnp.int32)
+            assigned_n = jnp.sum(matched.valid & (assignment >= 0), axis=-1)
+            bound = jnp.where(shards_matched > 0,
+                              assigned_n / jnp.maximum(shards_matched, 1), 1.0)
+            bound = jnp.where(ovf, jnp.nan, bound).astype(jnp.float32)
+        with jax.named_scope("query.scan"):
+            partials = scan_engine(state.tup_f, state.tup_sid,
+                                   state.tup_count, p, sublists, sublist_len,
+                                   use_kernel, interpret,
+                                   channels=agg.channels,
+                                   valid_c=cfg.tuple_capacity)
         outs.append((partials, sublist_len, ovf, shards_matched,
                      replicas_lost, bound))
 
@@ -987,39 +1020,40 @@ def finalize_query(partials, sublist_len, lookup_mask, broadcast, overflow,
     (and the meaningless mean) are masked to NaN — they must never leak into
     ``QueryResult`` as if they were data.
     """
-    count, vsum, vmin, vmax = partials
-    total = jnp.sum(count, axis=-1).astype(jnp.int32)            # (Q,)
-    vsum_total = jnp.sum(vsum, axis=-1)                          # (Q, K)
-    vmin_total = jnp.min(vmin, axis=-1)
-    vmax_total = jnp.max(vmax, axis=-1)
-    some = (total > 0)[:, None]                                  # (Q, 1)
-    vmin_total = jnp.where(some, vmin_total, jnp.nan)
-    vmax_total = jnp.where(some, vmax_total, jnp.nan)
-    vmean = jnp.where(some, vsum_total / jnp.maximum(total, 1)[:, None],
-                      jnp.nan)
-    if vsum_total.shape[-1] == 1:    # single-channel spec: classic (Q,) shape
-        vsum_total, vmin_total, vmax_total, vmean = (
-            a[:, 0] for a in (vsum_total, vmin_total, vmax_total, vmean))
-    result = QueryResult(
-        count=total,
-        vsum=vsum_total,
-        vmin=vmin_total,
-        vmax=vmax_total,
-        overflow=overflow,
-        vmean=vmean,
-        completeness_bound=completeness_bound,
-        replicas_lost=replicas_lost,
-    )
-    info = QueryInfo(
-        lookup_edges=jnp.sum(lookup_mask, axis=-1),
-        subquery_edges=jnp.sum(sublist_len != 0, axis=-1),
-        shards_matched=shards_matched,
-        max_shards_per_edge=jnp.max(jnp.abs(sublist_len), axis=-1),
-        broadcast=broadcast,
-        replicas_lost=replicas_lost,
-        completeness_bound=completeness_bound,
-    )
-    return result, info
+    with jax.named_scope("query.combine"):
+        count, vsum, vmin, vmax = partials
+        total = jnp.sum(count, axis=-1).astype(jnp.int32)        # (Q,)
+        vsum_total = jnp.sum(vsum, axis=-1)                      # (Q, K)
+        vmin_total = jnp.min(vmin, axis=-1)
+        vmax_total = jnp.max(vmax, axis=-1)
+        some = (total > 0)[:, None]                              # (Q, 1)
+        vmin_total = jnp.where(some, vmin_total, jnp.nan)
+        vmax_total = jnp.where(some, vmax_total, jnp.nan)
+        vmean = jnp.where(some, vsum_total / jnp.maximum(total, 1)[:, None],
+                          jnp.nan)
+        if vsum_total.shape[-1] == 1:    # single-channel: classic (Q,)
+            vsum_total, vmin_total, vmax_total, vmean = (
+                a[:, 0] for a in (vsum_total, vmin_total, vmax_total, vmean))
+        result = QueryResult(
+            count=total,
+            vsum=vsum_total,
+            vmin=vmin_total,
+            vmax=vmax_total,
+            overflow=overflow,
+            vmean=vmean,
+            completeness_bound=completeness_bound,
+            replicas_lost=replicas_lost,
+        )
+        info = QueryInfo(
+            lookup_edges=jnp.sum(lookup_mask, axis=-1),
+            subquery_edges=jnp.sum(sublist_len != 0, axis=-1),
+            shards_matched=shards_matched,
+            max_shards_per_edge=jnp.max(jnp.abs(sublist_len), axis=-1),
+            broadcast=broadcast,
+            replicas_lost=replicas_lost,
+            completeness_bound=completeness_bound,
+        )
+        return result, info
 
 
 @partial(jax.jit, static_argnums=(0, 5, 6, 7))

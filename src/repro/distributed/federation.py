@@ -34,6 +34,11 @@ recomputed replicated. ``tests/test_federation.py`` is the differential
 harness proving the single-device, 1-D, and 2-D paths produce identical
 results and states.
 
+The jitted entry points are named for what they run — ``fed_insert``,
+``fed_ingest_rounds``, ``ingest_rounds`` (one device) and ``fed_query`` —
+and those names key the compile-count budgets in ``pyproject.toml`` and
+label the programs (``jit_fed_query``, ...) in profiler traces.
+
 Sustained ingest goes through ``ingest_rounds`` — a fused ``lax.scan`` over
 collection rounds that replaces Python-loop round-tripping (one dispatch, no
 per-round host sync) and **donates** the store so the tuple ring is updated
@@ -186,11 +191,11 @@ def _insert_fn(cfg: StoreConfig, mesh: Mesh):
         out_specs=(state_specs, _insert_info_specs(False, axes)),
         check_vma=False)
 
-    def step(state, payload, meta, alive):
+    def fed_insert(state, payload, meta, alive):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
         return sharded(state, payload, meta, alive, edge_ids)
 
-    return jax.jit(step)
+    return jax.jit(fed_insert)
 
 
 def federated_insert_step(cfg: StoreConfig, state: StoreState,
@@ -217,10 +222,10 @@ def _ingest_fn(cfg: StoreConfig, mesh: Optional[Mesh]):
         return jax.lax.scan(round_body, state, (payloads, metas))
 
     if mesh is None:
-        def single(state, payloads, metas, alive):
+        def ingest_rounds(state, payloads, metas, alive):
             edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
             return run(state, payloads, metas, alive, edge_ids)
-        return jax.jit(single, donate_argnums=(0,))
+        return jax.jit(ingest_rounds, donate_argnums=(0,))
 
     axes = mesh_edge_axes(mesh)
     state_specs = store_partition_specs(axes)
@@ -230,11 +235,11 @@ def _ingest_fn(cfg: StoreConfig, mesh: Optional[Mesh]):
         out_specs=(state_specs, _insert_info_specs(True, axes)),
         check_vma=False)
 
-    def multi(state, payloads, metas, alive):
+    def fed_ingest_rounds(state, payloads, metas, alive):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
         return sharded(state, payloads, metas, alive, edge_ids)
 
-    return jax.jit(multi, donate_argnums=(0,))
+    return jax.jit(fed_ingest_rounds, donate_argnums=(0,))
 
 
 def ingest_rounds(cfg: StoreConfig, state: StoreState, payloads, metas,
@@ -286,7 +291,7 @@ def _query_fn(cfg: StoreConfig, mesh: Mesh, use_kernel: bool,
     # reduction axis is the (edge-bearing) mesh axes in both cases.
     partial_specs = (P(None, axes),) + (P(None, None, axes),) * 3
 
-    def outer(state, pred, alive, key_data):
+    def fed_query(state, pred, alive, key_data):
         edge_ids = jnp.arange(cfg.n_edges, dtype=jnp.int32)
         sharded = jax.shard_map(
             body, mesh=mesh,
@@ -303,7 +308,7 @@ def _query_fn(cfg: StoreConfig, mesh: Mesh, use_kernel: bool,
         # meta_info — computed replicated next to planning, like the rest.
         return finalize_query(partials, sublist_len, *meta_info)
 
-    return jax.jit(outer)
+    return jax.jit(fed_query)
 
 
 def federated_query_step(cfg: StoreConfig, state: StoreState, pred,

@@ -52,6 +52,7 @@ from typing import Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.ingest.coalesce import group_shards, plan_chunks
 from repro.ingest.journal import WriteAheadJournal
@@ -168,6 +169,7 @@ class IngestPipeline:
         # maybe_flush deadline — armed lazily from the first call's clock,
         # so callers driving a synthetic ``now`` never mix clocks.
         self._flush_deadline: Optional[float] = None
+        self._n_flush_calls = 0  # tags each ``aerialdb.ingest.flush`` span
 
     # -- submit --------------------------------------------------------------
 
@@ -311,19 +313,45 @@ class IngestPipeline:
         (``gave_up`` counted — the caller returns the chunk's records to
         pending). ``PipelineCrash`` is deliberately not caught."""
         attempt = 0
-        while True:
-            try:
-                if self.fault_hook is not None:
-                    self.fault_hook(self, attempt)
-                fn(*args)
-                return True
-            except TransientDispatchError:
-                if attempt >= self.max_retries:
-                    self.counters["gave_up"] += 1
-                    return False
-                self.counters["retries"] += 1
-                self._sleep(self.backoff_s * self.backoff_factor ** attempt)
-                attempt += 1
+        with TraceAnnotation("aerialdb.ingest.dispatch"):
+            while True:
+                try:
+                    if self.fault_hook is not None:
+                        self.fault_hook(self, attempt)
+                    fn(*args)
+                    return True
+                except TransientDispatchError:
+                    if attempt >= self.max_retries:
+                        self.counters["gave_up"] += 1
+                        return False
+                    self.counters["retries"] += 1
+                    self._sleep(self.backoff_s
+                                * self.backoff_factor ** attempt)
+                    attempt += 1
+
+    def _plan_dispatches(self, batches: dict) -> list:
+        """Cut each shard-length group of ``group_shards`` into the chunk
+        sizes of ``plan_chunks``; a run of equal-size chunks becomes ONE
+        fused multi-round dispatch. Returns ``[(payloads (N, B, k, W),
+        metas with (N, B) fields, record indices)]`` in dispatch order."""
+        out = []
+        for k, (pay, meta, idx) in sorted(batches.items()):
+            b_max = max(self.batch_shards * self.r_full // max(k, 1), 1)
+            sizes = plan_chunks(pay.shape[0], b_max)
+            off = i = 0
+            while i < len(sizes):
+                j = i
+                while j < len(sizes) and sizes[j] == sizes[i]:
+                    j += 1
+                nb, b = j - i, sizes[i]
+                sl = slice(off, off + nb * b)
+                out.append((pay[sl].reshape(nb, b, k, self.width),
+                            type(meta)(*(np.asarray(f)[sl].reshape(nb, b)
+                                         for f in meta)),
+                            np.asarray(idx)[sl].reshape(-1)))
+                off += nb * b
+                i = j
+        return out
 
     def flush(self, drain: bool = False, block: bool = True) -> dict:
         """Coalesce pending records into shards and ingest them.
@@ -343,7 +371,19 @@ class IngestPipeline:
         ``returned_records``, and (when blocking) ``latency_s`` — the
         flushed records' submit->queryable wall times. ``on_flush`` fires
         (error-isolated) after local storage whenever records shipped.
+
+        Profiler spans: ``aerialdb.ingest.flush`` (tagged ``flush=<n>``, the
+        pipeline's flush-call count) around ``aerialdb.ingest.coalesce``
+        (concatenation, ``group_shards``, ``plan_chunks``), one
+        ``aerialdb.ingest.dispatch`` per dispatch, retries included, and
+        ``aerialdb.ingest.block`` (the final ``block_until_ready``).
         """
+        self._n_flush_calls += 1
+        with TraceAnnotation("aerialdb.ingest.flush",
+                             flush=self._n_flush_calls):
+            return self._flush(drain, block)
+
+    def _flush(self, drain: bool, block: bool) -> dict:
         retries0 = self.counters["retries"]
         gave0 = self.counters["gave_up"]
         if not self._pend:
@@ -352,47 +392,30 @@ class IngestPipeline:
                    "returned_records": 0, "latency_s": np.empty(0)}
             self.last_flush = out
             return out
-        drone = np.concatenate([p[0] for p in self._pend])
-        seq = np.concatenate([p[1] for p in self._pend])
-        rows = np.concatenate([p[2] for p in self._pend])
-        tsub = np.concatenate([p[3] for p in self._pend])
-        batches, leftover = group_shards(drone, seq, rows, self.r_full,
-                                         self._shard_seq, drain)
-        n_shards = n_records = dispatches = 0
+        with TraceAnnotation("aerialdb.ingest.coalesce"):
+            drone = np.concatenate([p[0] for p in self._pend])
+            seq = np.concatenate([p[1] for p in self._pend])
+            rows = np.concatenate([p[2] for p in self._pend])
+            tsub = np.concatenate([p[3] for p in self._pend])
+            batches, leftover = group_shards(drone, seq, rows, self.r_full,
+                                             self._shard_seq, drain)
+            chunks = self._plan_dispatches(batches)
+        n_shards = n_records = 0
         flushed_tsub = []
         failed_idx = []
-        for k, (pay, meta, idx) in sorted(batches.items()):
-            b_total = pay.shape[0]
-            b_max = max(self.batch_shards * self.r_full // max(k, 1), 1)
-            off = 0
-            sizes = plan_chunks(b_total, b_max)
-            i = 0
-            while i < len(sizes):
-                # Equal-size run -> ONE fused multi-round scan dispatch.
-                j = i
-                while j < len(sizes) and sizes[j] == sizes[i]:
-                    j += 1
-                nb, b = j - i, sizes[i]
-                sl = slice(off, off + nb * b)
-                pays = pay[sl].reshape(nb, b, k, self.width)
-                metas = type(meta)(*(np.asarray(f)[sl].reshape(nb, b)
-                                     for f in meta))
-                if nb == 1:
-                    ok = self._dispatch(
-                        self.db.insert, pays[0],
-                        type(meta)(*(f[0] for f in metas)))
-                else:
-                    ok = self._dispatch(self.db.ingest_rounds, pays, metas)
-                dispatches += 1
-                chunk_idx = np.asarray(idx)[sl].reshape(-1)
-                if ok:
-                    n_shards += nb * b
-                    n_records += chunk_idx.size
-                    flushed_tsub.append(tsub[chunk_idx])
-                else:
-                    failed_idx.append(chunk_idx)
-                off += nb * b
-                i = j
+        for pays, metas, chunk_idx in chunks:
+            if pays.shape[0] == 1:
+                ok = self._dispatch(self.db.insert, pays[0],
+                                    type(metas)(*(f[0] for f in metas)))
+            else:
+                ok = self._dispatch(self.db.ingest_rounds, pays, metas)
+            if ok:
+                n_shards += pays.shape[0] * pays.shape[1]
+                n_records += chunk_idx.size
+                flushed_tsub.append(tsub[chunk_idx])
+            else:
+                failed_idx.append(chunk_idx)
+        dispatches = len(chunks)
         # Keep the leftover (sub-shard) tails AND any gave-up chunks'
         # records pending. (Gave-up shards already consumed their sid_lo
         # numbers — the re-flush assigns fresh ones, which only needs sids
@@ -412,7 +435,8 @@ class IngestPipeline:
                "returned_records": int(sum(f.size for f in failed_idx)),
                "latency_s": np.empty(0)}
         if block:
-            jax.block_until_ready(self.db.state.tup_count)
+            with TraceAnnotation("aerialdb.ingest.block"):
+                jax.block_until_ready(self.db.state.tup_count)
             done = time.monotonic()
             if flushed_tsub:
                 out["latency_s"] = done - np.concatenate(flushed_tsub)
@@ -499,13 +523,15 @@ class IngestPipeline:
     def latest(self):
         """``(record (D, W), valid (D,))`` numpy — the store's hot cache
         with still-pending (in-flight) records overlaid, so the answer is
-        exact over everything ever *submitted*, not just flushed."""
-        res = self.db.latest()
-        record = np.array(res.record)
-        valid = np.array(res.valid)
-        for d, _s, rows, _t in self._pend:
-            overlay_latest(record, valid, d, rows[:, 0], rows)
-        return record, valid
+        exact over everything ever *submitted*, not just flushed. Runs
+        under the ``aerialdb.ingest.latest`` profiler span."""
+        with TraceAnnotation("aerialdb.ingest.latest"):
+            res = self.db.latest()
+            record = np.array(res.record)
+            valid = np.array(res.valid)
+            for d, _s, rows, _t in self._pend:
+                overlay_latest(record, valid, d, rows[:, 0], rows)
+            return record, valid
 
     # -- reconciliation ------------------------------------------------------
 

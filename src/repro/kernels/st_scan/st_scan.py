@@ -223,5 +223,6 @@ def st_scan_kernel(tupf_t, sid_t, tup_count, pred_f, pred_i, sublists,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="st_scan",
     )(tup_count, tupf_t, sid_t, pred_f, pred_i, sublists, slen_t)
     return tuple(out)
