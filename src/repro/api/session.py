@@ -152,7 +152,7 @@ class AerialDB:
                  shard_map path. None = single-device jit path.
           seed:  planner PRNG seed (the facade owns and splits the key).
           use_kernel / interpret: scan-engine selection, as in
-                 ``scan_engine`` (Pallas TPU kernel vs jnp reference).
+                 ``scan_engine`` (Pallas TPU kernel vs jnp engine).
           **cfg_overrides: with ``cfg=None``, StoreConfig fields; with a
                  config given, ``dataclasses.replace`` overrides.
         """

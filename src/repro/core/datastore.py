@@ -65,7 +65,8 @@ still holds; loss is bounded by the replicas' retention skew.
 
 The per-edge query engine (the paper's InfluxDB role) is a predicate scan —
 ``repro.kernels.st_scan`` provides the Pallas TPU kernel; ``scan_engine`` here
-dispatches to it or to the jnp reference.
+dispatches to it or to the jnp engine (``st_scan.chunked``), whose OR-list
+membership test runs only as many entries as the longest per-edge list.
 
 Phase names: every phase of ``query_local`` (``query.lookup``,
 ``query.merge``, ``query.plan``, ``query.orlist``, ``query.scan``),
@@ -823,7 +824,9 @@ def scan_engine(tup_f, tup_sid, tup_count, pred: QueryPred, sublists,
                    StoreState layout, streamed as-is (no relayout).
       sublists:    (Q, E, L, 2) int32 shard ids assigned to each (query, edge).
       sublist_len: (Q, E) int32 — #valid entries in each OR-list.
-      use_kernel:  dispatch to the Pallas TPU kernel instead of the jnp ref.
+      use_kernel:  dispatch to the Pallas TPU kernel instead of the jnp
+                   engine (``st_scan_chunked``: the oracle's results, its
+                   OR-list test bounded by the longest per-edge list).
       interpret:   force Pallas interpret mode; None = auto (compiled on TPU,
                    interpreted elsewhere).
       channels:    static tuple of sensor channels to aggregate
@@ -839,9 +842,9 @@ def scan_engine(tup_f, tup_sid, tup_count, pred: QueryPred, sublists,
         return st_ops.st_scan(tup_f, tup_sid, tup_count, pred, sublists,
                               sublist_len, interpret=interpret,
                               channels=channels, valid_c=valid_c)
-    from repro.kernels.st_scan import ref as st_ref
-    return st_ref.st_scan_ref(tup_f, tup_sid, tup_count, pred, sublists,
-                              sublist_len, channels=channels, valid_c=valid_c)
+    from repro.kernels.st_scan.chunked import st_scan_chunked
+    return st_scan_chunked(tup_f, tup_sid, tup_count, pred, sublists,
+                           sublist_len, channels=channels, valid_c=valid_c)
 
 
 def _tile_slices(q: int, n_tiles: int):

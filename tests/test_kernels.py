@@ -15,6 +15,7 @@ from repro.kernels.hash64 import ref as href
 from repro.kernels.hash64.hash64 import xxh64
 from repro.kernels.st_scan import ops as st_ops
 from repro.kernels.st_scan import ref as st_ref
+from repro.kernels.st_scan.chunked import CHUNK, st_scan_chunked
 from repro.kernels.voronoi_assign import ref as vref
 from repro.kernels.voronoi_assign.voronoi_assign import voronoi_assign
 
@@ -323,6 +324,51 @@ def test_st_scan_lane_padded_capacity_post_wrap(interpret):
         for g, x, name in zip(got[1:], exp[1:], ["vsum", "vmin", "vmax"]):
             np.testing.assert_allclose(np.asarray(g), np.asarray(x),
                                        rtol=1e-5, err_msg=name)
+
+
+# OR-list width of the chunked-engine cases: not a multiple of CHUNK, so the
+# last chunk is padded.
+_CHUNKED_L = 2 * CHUNK + 5
+
+
+@pytest.mark.parametrize("longest", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1,
+                                     2 * CHUNK + 3, _CHUNKED_L, -1])
+def test_st_scan_chunked_matches_ref(longest):
+    """The served jnp engine tests OR-list membership only up to the longest
+    list of the batch, CHUNK entries a step: it must equal the oracle bit
+    for bit, with lengths mixed across (query, edge) pairs, the scan-all
+    sentinel among them, and a lane-padded capacity (valid_c < C). Entries
+    past each pair's length are real shard ids, so reading one shows."""
+    rng = np.random.default_rng(100 + longest)
+    q, e, cap, pad = 3, 4, 500, 140
+    args = list(random_scan_problem(rng, e=e, c=cap + pad, q=q,
+                                    l=_CHUNKED_L))
+    # 64 distinct (hi, lo) shard ids, each held by ~1/64 of the slots; every
+    # list draws from ids 1-63 without repeats, so each entry decides some
+    # tuples and id (0, 0), which padding could alias, is in no list.
+    sid = rng.integers(0, 64, (e, cap + pad))
+    args[1] = jnp.asarray(np.stack([sid // 16, sid % 16], axis=1), jnp.int32)
+    perm = np.stack([1 + rng.permutation(63)[:_CHUNKED_L]
+                     for _ in range(q * e)]).reshape(q, e, _CHUNKED_L)
+    args[4] = jnp.asarray(np.stack([perm // 16, perm % 16], -1), jnp.int32)
+    top = abs(longest)
+    slen = rng.choice([0, -1, top, top // 2, min(top, 1)], (q, e))
+    slen[0, 0] = longest
+    if longest >= 0:
+        slen[slen < 0] = 0 if longest == 0 else 1
+    if longest == _CHUNKED_L:      # a length past the list: L entries count
+        slen[1, 1] = _CHUNKED_L + CHUNK
+    args[5] = jnp.asarray(slen, jnp.int32)
+    args[2] = jnp.asarray(rng.integers(cap + 1, 4 * cap, (e,)), jnp.int32)
+    channels = (0, 2, 3)
+    ref = jax.jit(st_ref.st_scan_ref, static_argnames=("channels", "valid_c"))
+    got_fn = jax.jit(st_scan_chunked, static_argnames=("channels", "valid_c"))
+    exp = ref(*args, channels=channels, valid_c=cap)
+    got = got_fn(*args, channels=channels, valid_c=cap)
+    assert (int(np.asarray(exp[0]).sum()) > 0) == (longest != 0)
+    for g, x, name in zip(got, exp, ["count", "vsum", "vmin", "vmax"]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x),
+                                      err_msg=name)
 
 
 @settings(deadline=None, max_examples=25)

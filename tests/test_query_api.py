@@ -29,6 +29,7 @@ from repro.core.datastore import StoreConfig, init_store, insert_step, query_ste
 from repro.core.index import QueryPred
 from repro.core.placement import ShardMeta
 from repro.data.synthetic import CityConfig, DroneFleet, make_sites
+from repro.kernels.st_scan.chunked import CHUNK
 
 E = 8
 
@@ -343,6 +344,39 @@ def test_channel_out_of_range_raises(loaded_db):
     db, _, _ = loaded_db
     with pytest.raises(ValueError, match="channel=7 out of range"):
         db.query(Query().time(0, 1).agg("count", channel=7))
+
+
+def test_long_or_lists_take_several_chunks():
+    """min_edges piles a query's shards onto as few edges as it can, so one
+    edge's OR-list runs past 2 * CHUNK entries and the served engine's
+    membership loop takes three steps or more: the answers must still equal
+    a numpy scan of every record."""
+    n_edges = 4
+    sites = make_sites(n_edges, CityConfig(), seed=3)
+    cfg = StoreConfig(n_edges=n_edges, sites=tuple(map(tuple, sites.tolist())),
+                      tuple_capacity=4096, index_capacity=512,
+                      max_shards_per_query=128, records_per_shard=12,
+                      planner="min_edges")
+    db = AerialDB.open(cfg)
+    payloads, metas = DroneFleet(16, records_per_shard=12,
+                                 seed=7).next_rounds(6)
+    db.ingest_rounds(payloads, metas)
+    flat = payloads.reshape(-1, payloads.shape[-1])
+    t_mid = float(np.median(flat[:, 0]))
+    windows = [(0.0, 1e9), (0.0, t_mid), (t_mid, 1e9)]
+    pred, _ = Query.batch(*(Query().time(t0, t1) for t0, t1 in windows))
+    channels = (0, 3)
+    res, info = db.query(pred, agg=AggSpec(channels=channels))
+    assert int(np.asarray(info.max_shards_per_edge)[0]) > 2 * CHUNK
+    for qi, (t0, t1) in enumerate(windows):
+        m = (t0 <= flat[:, 0]) & (flat[:, 0] <= t1)
+        assert int(res.count[qi]) == int(m.sum())
+        for k, ch in enumerate(channels):
+            v = flat[m, 3 + ch]
+            np.testing.assert_allclose(float(res.vsum[qi, k]), v.sum(),
+                                       rtol=1e-4)
+            assert float(res.vmin[qi, k]) == v.min()
+            assert float(res.vmax[qi, k]) == v.max()
 
 
 # ---------------------------------------------------------------------------
