@@ -39,6 +39,15 @@ The jitted entry points are named for what they run — ``fed_insert``,
 and those names key the compile-count budgets in ``pyproject.toml`` and
 label the programs (``jit_fed_query``, ...) in profiler traces.
 
+Trace names of what the federation adds. Device scopes: the candidate
+merge's all-gathers run under ``query.exchange`` (inside ``query.merge``,
+whose re-deduplication stays ``query.merge``) and the watermark all-gather
+under ``insert.exchange``; like ``core.datastore``'s scopes they label only
+``op_name`` metadata. Host spans, tagged ``program=<jit name>``, in each
+federated step: ``aerialdb.fed.check`` (mesh and batch checks, argument
+preparation) and ``aerialdb.fed.call`` (the jitted call until it returns,
+before its result is ready). The one-device paths carry none of them.
+
 Sustained ingest goes through ``ingest_rounds`` — a fused ``lax.scan`` over
 collection rounds that replaces Python-loop round-tripping (one dispatch, no
 per-round host sync) and **donates** the store so the tuple ring is updated
@@ -58,6 +67,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -122,19 +132,24 @@ def _gather_watermark(wm_local: jnp.ndarray, axes: tuple) -> jnp.ndarray:
     """(E_local,) -> (E,) over the edge-bearing axis product. A tuple-axis
     all-gather concatenates major axis outermost — exactly the fleet-major
     edge-block order of the layout contract."""
-    return jax.lax.all_gather(wm_local, axes, axis=0, tiled=True)
+    with jax.named_scope("insert.exchange"):
+        return jax.lax.all_gather(wm_local, axes, axis=0, tiled=True)
 
 
 def _merge_axis(local: MatchedShards, max_shards: int,
                 axis: str) -> MatchedShards:
     """One merge level: all-gather each participant's top-S list along one
-    mesh axis and re-deduplicate back down to top-S."""
+    mesh axis and re-deduplicate back down to top-S. The all-gathers run
+    under ``query.exchange``, in the order the unlabelled code emits them."""
     cat = lambda x: jax.lax.all_gather(x, axis, axis=1, tiled=True)
-    merged = dedup_matched(cat(local.valid), cat(local.sid_hi),
-                           cat(local.sid_lo), cat(local.replicas), max_shards)
-    any_local_ovf = jnp.any(
-        jax.lax.all_gather(local.overflow, axis, axis=0, tiled=False),
-        axis=0)
+    with jax.named_scope("query.exchange"):
+        lists = [cat(x) for x in (local.valid, local.sid_hi, local.sid_lo,
+                                  local.replicas)]
+    merged = dedup_matched(*lists, max_shards)
+    with jax.named_scope("query.exchange"):
+        local_ovf = jax.lax.all_gather(local.overflow, axis, axis=0,
+                                       tiled=False)
+    any_local_ovf = jnp.any(local_ovf, axis=0)
     return merged._replace(overflow=merged.overflow | any_local_ovf)
 
 
@@ -203,9 +218,11 @@ def federated_insert_step(cfg: StoreConfig, state: StoreState,
                           alive: jnp.ndarray, mesh: Mesh):
     """``insert_step`` over a datastore mesh: identical semantics, state
     sharded per ``store_partition_specs``, device-local tuple/index writes."""
-    check_edge_mesh(cfg, mesh)
-    check_batch_fits(cfg, payload.shape)
-    return _insert_fn(cfg, mesh)(state, payload, meta, alive)
+    with TraceAnnotation("aerialdb.fed.check", program="fed_insert"):
+        check_edge_mesh(cfg, mesh)
+        check_batch_fits(cfg, payload.shape)
+    with TraceAnnotation("aerialdb.fed.call", program="fed_insert"):
+        return _insert_fn(cfg, mesh)(state, payload, meta, alive)
 
 
 @lru_cache(maxsize=None)
@@ -258,12 +275,22 @@ def ingest_rounds(cfg: StoreConfig, state: StoreState, payloads, metas,
 
     Returns (state, info) with every info entry stacked over the N rounds.
     """
+    if mesh is None:
+        payloads, metas = _ingest_args(cfg, payloads, metas)
+        return _ingest_fn(cfg, None)(state, payloads, metas, alive)
+    with TraceAnnotation("aerialdb.fed.check", program="fed_ingest_rounds"):
+        payloads, metas = _ingest_args(cfg, payloads, metas)
+        check_edge_mesh(cfg, mesh)
+    with TraceAnnotation("aerialdb.fed.call", program="fed_ingest_rounds"):
+        return _ingest_fn(cfg, mesh)(state, payloads, metas, alive)
+
+
+def _ingest_args(cfg: StoreConfig, payloads, metas):
+    """``ingest_rounds``' arguments on the device, the batch checked."""
     payloads = jnp.asarray(payloads)
     metas = ShardMeta(*[jnp.asarray(x) for x in metas])
     check_batch_fits(cfg, payloads.shape[1:])
-    if mesh is not None:
-        check_edge_mesh(cfg, mesh)
-    return _ingest_fn(cfg, mesh)(state, payloads, metas, alive)
+    return payloads, metas
 
 
 @lru_cache(maxsize=None)
@@ -325,7 +352,10 @@ def federated_query_step(cfg: StoreConfig, state: StoreState, pred,
     derived mean) stays the only cross-device reduction. Only
     ``agg.channels`` keys the compiled-function cache — varying the ops
     projection is free. Returns (QueryResult, QueryInfo)."""
-    check_edge_mesh(cfg, mesh)
-    agg.validate_for(cfg)
-    return _query_fn(cfg, mesh, use_kernel, interpret, agg.channels)(
-        state, pred, alive, jax.random.key_data(key))
+    with TraceAnnotation("aerialdb.fed.check", program="fed_query"):
+        check_edge_mesh(cfg, mesh)
+        agg.validate_for(cfg)
+        key_data = jax.random.key_data(key)
+    with TraceAnnotation("aerialdb.fed.call", program="fed_query"):
+        return _query_fn(cfg, mesh, use_kernel, interpret, agg.channels)(
+            state, pred, alive, key_data)

@@ -6,14 +6,19 @@ Three contracts:
    ``insert_local`` (``query.*``, ``insert.*``) names ops of the lowered
    single-device programs and of the federated programs on the ``(4,)``
    mesh of virtual devices — the names a profiler trace carries as each
-   op's ``op_name``.
+   op's ``op_name``. The federation's exchange scopes (``query.exchange``,
+   ``insert.exchange``) name every all-gather of the federated programs and
+   nothing of the single-device ones, and change no program but in its
+   metadata.
 2. A CPU profiler trace of one ``AerialDB.query``, one
    ``IngestPipeline.flush`` and one ``IngestPipeline.latest`` holds the
-   ``aerialdb.*`` host spans, nested as documented, with the sequence tags.
+   ``aerialdb.*`` host spans, nested as documented, with the sequence tags;
+   on the ``(4,)`` mesh the federated steps' ``aerialdb.fed.*`` spans too.
 3. Tracing is observation only: answers and stored state are bit-identical
    with a trace running and without one.
 """
 
+import contextlib
 import re
 
 import jax
@@ -81,33 +86,44 @@ def _op_scopes(lowered) -> set:
     return {part for n in names for part in n.split("/")}
 
 
+def _lowered(program: str):
+    """One program lowered afresh at the tiny size: ``single_query``,
+    ``single_insert`` (one device) or ``fed_query``, ``fed_insert``,
+    ``fed_ingest_rounds`` (the ``(4,)`` mesh of virtual devices)."""
+    pay, meta = _batch()
+    alive = jnp.ones(E, bool)
+    if program.startswith("single"):
+        cfg = _cfg()
+        state = init_store(cfg)
+        if program == "single_query":
+            return _query_step_jit.lower(cfg, state, _pred(), alive,
+                                         jax.random.key(0), False, None,
+                                         (0, 1))
+        return _insert_step_jit.lower(cfg, state, pay, meta, alive)
+    mesh = make_edge_mesh(4, n_edges=E)
+    cfg = _cfg(n_failure_domains=4)
+    state = fed.shard_store(init_store(cfg), mesh)
+    if program == "fed_query":
+        return fed._query_fn(cfg, mesh, False, None, (0, 1)).lower(
+            state, _pred(), alive, jax.random.key_data(jax.random.key(0)))
+    if program == "fed_insert":
+        return fed._insert_fn(cfg, mesh).lower(state, pay, meta, alive)
+    pays = jnp.stack([pay, pay])
+    metas = ShardMeta(*(jnp.stack([f, f]) for f in meta))
+    return fed._ingest_fn(cfg, mesh).lower(state, pays, metas, alive)
+
+
 @pytest.fixture(scope="module")
 def single_scopes():
-    cfg = _cfg()
-    state = init_store(cfg)
-    alive = jnp.ones(E, bool)
-    pay, meta = _batch()
-    q = _query_step_jit.lower(cfg, state, _pred(), alive, jax.random.key(0),
-                              False, None, (0, 1))
-    i = _insert_step_jit.lower(cfg, state, pay, meta, alive)
-    return {"query": _op_scopes(q), "insert": _op_scopes(i)}
+    return {"query": _op_scopes(_lowered("single_query")),
+            "insert": _op_scopes(_lowered("single_insert"))}
 
 
 @pytest.fixture(scope="module")
 def federated_scopes():
-    mesh = make_edge_mesh(4, n_edges=E)
-    cfg = _cfg(n_failure_domains=4)
-    state = fed.shard_store(init_store(cfg), mesh)
-    alive = jnp.ones(E, bool)
-    pay, meta = _batch()
-    q = fed._query_fn(cfg, mesh, False, None, (0, 1)).lower(
-        state, _pred(), alive, jax.random.key_data(jax.random.key(0)))
-    i = fed._insert_fn(cfg, mesh).lower(state, pay, meta, alive)
-    pays = jnp.stack([pay, pay])
-    metas = ShardMeta(*(jnp.stack([f, f]) for f in meta))
-    n = fed._ingest_fn(cfg, mesh).lower(state, pays, metas, alive)
-    return {"query": _op_scopes(q), "insert": _op_scopes(i),
-            "ingest_rounds": _op_scopes(n)}
+    return {"query": _op_scopes(_lowered("fed_query")),
+            "insert": _op_scopes(_lowered("fed_insert")),
+            "ingest_rounds": _op_scopes(_lowered("fed_ingest_rounds"))}
 
 
 @pytest.mark.parametrize("scope", QUERY_SCOPES)
@@ -120,16 +136,71 @@ def test_single_device_insert_program_names_its_phases(single_scopes, scope):
     assert scope in single_scopes["insert"]
 
 
-@pytest.mark.parametrize("scope", QUERY_SCOPES + ["query.merge"])
+@pytest.mark.parametrize("scope",
+                         QUERY_SCOPES + ["query.merge", "query.exchange"])
 def test_federated_query_program_names_its_phases(federated_scopes, scope):
     assert scope in federated_scopes["query"]
 
 
 @pytest.mark.parametrize("program", ["insert", "ingest_rounds"])
-@pytest.mark.parametrize("scope", INSERT_SCOPES)
+@pytest.mark.parametrize("scope", INSERT_SCOPES + ["insert.exchange"])
 def test_federated_insert_programs_name_their_phases(federated_scopes,
                                                      program, scope):
     assert scope in federated_scopes[program]
+
+
+def _all_gather_op_names(lowered) -> list:
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.split("\n") if " all-gather(" in line]
+
+
+@pytest.mark.parametrize("program, scope, n", [
+    ("fed_query", "query.exchange", 5),   # four candidate lists, overflow
+    ("fed_insert", "insert.exchange", 1),  # the retention watermark
+])
+def test_every_all_gather_runs_in_the_exchange_scope(program, scope, n):
+    names = _all_gather_op_names(_lowered(program))
+    assert len(names) == n
+    assert all(scope in name.split("/") for name in names), names
+
+
+@pytest.fixture
+def fresh_programs():
+    """Every jitted program traced anew, before and after the test."""
+    def clear():
+        for fn in (fed._query_fn, fed._insert_fn, fed._ingest_fn):
+            fn.cache_clear()
+        jax.clear_caches()
+    clear()
+    yield
+    clear()
+
+
+@pytest.mark.parametrize("program", ["single_query", "single_insert",
+                                     "fed_query", "fed_insert"])
+def test_exchange_scopes_change_only_metadata(program, fresh_programs,
+                                              monkeypatch):
+    """Each program lowered with the exchange scopes and with them taken
+    out is the same program: the single-device ones carry neither scope and
+    name the same ops, the federated ones differ in op names alone."""
+    scoped = _lowered(program)
+    names = _op_scopes(scoped)
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+        if name.endswith(".exchange") else real(name))
+    for fn in (fed._query_fn, fed._insert_fn):
+        fn.cache_clear()
+    jax.clear_caches()
+    plain = _lowered(program)
+    assert scoped.as_text() == plain.as_text()
+    exchange = {"query.exchange", "insert.exchange"} & names
+    if program.startswith("single"):
+        assert not exchange
+        assert names == _op_scopes(plain)
+    else:
+        assert exchange and not exchange & _op_scopes(plain)
 
 
 @pytest.mark.parametrize("jitted, name", [
@@ -179,8 +250,14 @@ def _served(db, pipe, t0):
 
 
 @pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    db = AerialDB.open(_cfg(), seed=5)
+def traced(request, tmp_path_factory):
+    """Host spans of a warm session's flush, query and live read: on one
+    device, or on the ``(4,)`` mesh where the parameter is ``"fed4"``."""
+    if getattr(request, "param", None) == "fed4":
+        db = AerialDB.open(_cfg(n_failure_domains=4), seed=5,
+                           mesh=make_edge_mesh(4, n_edges=E))
+    else:
+        db = AerialDB.open(_cfg(), seed=5)
     pipe = IngestPipeline(db, batch_shards=4)
     _served(db, pipe, 0.0)                  # compile outside the trace
     trace_dir = tmp_path_factory.mktemp("trace")
@@ -190,22 +267,50 @@ def traced(tmp_path_factory):
     return _host_spans(trace_dir)
 
 
-def _one(spans, name):
-    found = [s for s in spans if s[2] == name]
+def _one(spans, name, **stats):
+    """The one span named ``name`` whose stats hold ``stats``."""
+    found = [s for s in spans
+             if s[2] == name and stats.items() <= s[3].items()]
     assert len(found) == 1, (name, [s[2] for s in spans])
     return found[0]
 
 
-@pytest.mark.parametrize("child, parent", [
-    ("aerialdb.query.prepare", "aerialdb.query"),
-    ("aerialdb.query.dispatch", "aerialdb.query"),
-    ("aerialdb.ingest.coalesce", "aerialdb.ingest.flush"),
-    ("aerialdb.ingest.dispatch", "aerialdb.ingest.flush"),
-    ("aerialdb.ingest.block", "aerialdb.ingest.flush"),
-    ("aerialdb.insert", "aerialdb.ingest.dispatch"),
-])
+NEST = [("aerialdb.query.prepare", "aerialdb.query"),
+        ("aerialdb.query.dispatch", "aerialdb.query"),
+        ("aerialdb.ingest.coalesce", "aerialdb.ingest.flush"),
+        ("aerialdb.ingest.dispatch", "aerialdb.ingest.flush"),
+        ("aerialdb.ingest.block", "aerialdb.ingest.flush"),
+        ("aerialdb.insert", "aerialdb.ingest.dispatch")]
+# The federated steps' spans, each tagged with its program, inside the
+# facade's span of the call.
+FED_NEST = [(f"aerialdb.fed.{part}", program, parent)
+            for program, parent in [("fed_query", "aerialdb.query.dispatch"),
+                                    ("fed_insert", "aerialdb.insert")]
+            for part in ("check", "call")]
+
+
+@pytest.mark.parametrize("traced, child, parent", [
+    *[pytest.param(None, c, p, id=f"{c}-{p}") for c, p in NEST],
+    *[pytest.param("fed4", c, p, id=f"fed4-{c}-{p}") for c, p in NEST],
+    *[pytest.param("fed4", (c, prog), p, id=f"fed4-{c}-{prog}-{p}")
+      for c, prog, p in FED_NEST],
+], indirect=["traced"])
 def test_host_spans_nest(traced, child, parent):
-    assert _inside(_one(traced, child), _one(traced, parent))
+    name, program = child if isinstance(child, tuple) else (child, None)
+    tag = {"program": program} if program else {}
+    assert _inside(_one(traced, name, **tag), _one(traced, parent))
+
+
+@pytest.mark.parametrize("traced", ["fed4"], indirect=True)
+def test_federated_steps_follow_each_other(traced):
+    for program in ("fed_query", "fed_insert"):
+        check = _one(traced, "aerialdb.fed.check", program=program)
+        assert check[1] <= _one(traced, "aerialdb.fed.call",
+                                program=program)[0]
+
+
+def test_one_device_session_opens_no_federated_span(traced):
+    assert not [s for s in traced if s[2].startswith("aerialdb.fed.")]
 
 
 def test_host_spans_follow_each_other(traced):
