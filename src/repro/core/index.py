@@ -220,17 +220,24 @@ def dedup_matched(matched: jnp.ndarray, sid_hi: jnp.ndarray, sid_lo: jnp.ndarray
       sid_lo:   (Q, N) int32.
       replicas: (Q, N, 3) int32.
     """
+    # The sorted columns come out of the sorts themselves: no gather runs
+    # over all N candidates, and the replica columns are gathered only at the
+    # max_shards selected positions. The stable sorts with the original index
+    # as the last operand keep each sid's first occurrence in flat order.
     def one_query(m, hi, lo, rep):
-        order = jnp.lexsort((lo, hi, ~m))
-        m_s, hi_s, lo_s = m[order], hi[order], lo[order]
-        rep_s = rep[order]
+        iota = jnp.arange(m.shape[0], dtype=jnp.int32)
+        nm_s, hi_s, lo_s, perm = jax.lax.sort((~m, hi, lo, iota), num_keys=3,
+                                              is_stable=True)
+        m_s = ~nm_s
         prev_same = jnp.concatenate([jnp.array([False]),
                                      (hi_s[1:] == hi_s[:-1]) & (lo_s[1:] == lo_s[:-1]) & m_s[:-1]])
         is_new = m_s & ~prev_same
         n_unique = jnp.sum(is_new)
-        order2 = jnp.lexsort((jnp.arange(m.shape[0]), ~is_new))[:max_shards]
-        return (hi_s[order2], lo_s[order2], rep_s[order2],
-                is_new[order2], n_unique > max_shards)
+        _, *sel = jax.lax.sort((~is_new, hi_s, lo_s, perm), num_keys=1,
+                               is_stable=True)
+        hi_sel, lo_sel, perm_sel = (x[:max_shards] for x in sel)
+        valid = jnp.arange(hi_sel.shape[0]) < n_unique
+        return (hi_sel, lo_sel, rep[perm_sel], valid, n_unique > max_shards)
 
     hi2, lo2, rep2, val2, ovf = jax.vmap(one_query)(matched, sid_hi, sid_lo,
                                                     replicas)
